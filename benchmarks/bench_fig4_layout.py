@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"       # a virtual CPU pool, never a chip
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=32 "
                            "--xla_backend_optimization_level=0 "
                            "--xla_llvm_disable_expensive_passes=true")

@@ -12,8 +12,9 @@ config:
 - **decode**: steady-state tokens/s over a full continuous-batching run
   plus p50/p99 per-request latency (submit -> finalize).
 
-Kernel routing follows the launcher default (Pallas on TPU, pure-JAX
-reference elsewhere; ``--pallas-attn`` / REPRO_PALLAS_ATTN override).
+Kernel routing follows the launcher default (the pure-JAX path until the
+Pallas attention kernels compile for v5e; ``--pallas-attn`` /
+REPRO_PALLAS_ATTN override).
 On the CPU stand-in the numbers measure the reference/interpret path —
 labeled via the ``backend`` / ``interpret`` fields — and become
 meaningful on TPU; the SHAPE of the comparison (chunked vs sequential
@@ -164,8 +165,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--pallas-attn", action=argparse.BooleanOptionalAction,
                     default=None,
-                    help="kernel routing (default: on on TPU, off "
-                         "elsewhere; env REPRO_PALLAS_ATTN overrides)")
+                    help="kernel routing (default: off; env "
+                         "REPRO_PALLAS_ATTN overrides)")
     ap.add_argument("--out", default=OUT_PATH)
     args = ap.parse_args(argv)
     print(f"bench_serve_lm: {args.arch} (reduced), slots={args.slots}, "

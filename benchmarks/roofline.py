@@ -20,6 +20,7 @@ import argparse
 import json
 import math
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"       # shapes and jaxprs only, never a chip
 
 from repro.cloud import costs as cost_lib
 from repro.configs import base as config_base

@@ -86,11 +86,14 @@ def _conv_layer(x, w, b=None, stride=1, *, activation="none", slope=0.2,
         # w stays in param dtype: the kernel casts for compute, the custom
         # vjp hands back dw in param dtype (bf16 policy safe)
         return op(x, w, bias, stride, activation, slope, None)
-    out = (jax.lax.conv_transpose(x, w.astype(x.dtype), (stride,) * 3,
-                                  "SAME", dimension_numbers=DN)
-           if transpose else
-           jax.lax.conv_general_dilated(x, w.astype(x.dtype), (stride,) * 3,
-                                        "SAME", dimension_numbers=DN))
+    if transpose:
+        out = jax.lax.conv_transpose(x, w.astype(x.dtype), (stride,) * 3,
+                                     "SAME", dimension_numbers=DN)
+    elif x.shape[-1] == 1:
+        out = _single_channel_conv(x, w, stride)
+    else:
+        out = jax.lax.conv_general_dilated(x, w.astype(x.dtype), (stride,) * 3,
+                                           "SAME", dimension_numbers=DN)
     if b is not None:
         out = out + b.astype(out.dtype)
     if activation == "leaky_relu":
@@ -98,6 +101,16 @@ def _conv_layer(x, w, b=None, stride=1, *, activation="none", slope=0.2,
     elif activation == "softplus":
         out = jax.nn.softplus(out)
     return out
+
+
+def _single_channel_conv(x, w, stride):
+    """A conv over a 1-channel input as patches @ kernel: the same sum, but
+    its weight gradient is one matmul.  Written as a conv, that gradient
+    alone (the discriminator's first layer at 51x51x25, batch 128) takes
+    the v5e compiler ~48 s; this form ~2 s."""
+    patches = jax.lax.conv_general_dilated_patches(
+        x, w.shape[:3], (stride,) * 3, "SAME", dimension_numbers=DN)
+    return patches @ w.reshape(-1, w.shape[-1]).astype(x.dtype)
 
 
 def _start_dims(image_shape, ups: int) -> Tuple[int, int, int]:

@@ -262,20 +262,16 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 
 
 def default_use_pallas(env_var: str) -> bool:
-    """Launcher-level kernel-routing default: ON on real TPUs, OFF
-    elsewhere, overridable per flag family via its env var (``1`` /
-    ``true`` / ``yes`` / ``on`` force on; ``0`` / ``false`` / ``no`` /
-    ``off`` force off).  Resolved once at launcher startup and frozen
-    into the ArchConfig, so the routing decision is trace-time static
-    like every other config field.
+    """Launcher-level kernel-routing default: OFF on every backend unless
+    the flag family's env var is ``1`` / ``true`` / ``yes`` / ``on``.
+    Off because the v5e compiler still refuses the attention and
+    SSM kernels at the LM widths (``tests/test_tpu_compile.py`` pins each
+    refusal); a family's default may turn on once its AOT cases compile.
+    Resolved once at launcher startup and frozen into the ArchConfig, so
+    the routing decision is trace-time static like every other config
+    field.
     """
-    env = os.environ.get(env_var, "").lower()
-    if env in ("1", "true", "yes", "on"):
-        return True
-    if env in ("0", "false", "no", "off"):
-        return False
-    import jax
-    return jax.default_backend() == "tpu"
+    return os.environ.get(env_var, "").lower() in ("1", "true", "yes", "on")
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ Usage:
   python -m repro.launch.dryrun --arch calo3dgan --multi-pod
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"       # a virtual CPU pool, never a chip
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
 # ^ MUST precede any jax import: jax locks the device count on first init.

@@ -127,6 +127,17 @@ TOPOLOGIES = {
 }
 
 
+def make_mesh(shape, axes, devices=None):
+    """The repo's one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, which
+    ``with_sharding_constraint`` and the builtin loop's GSPMD placement
+    reject; building every mesh here keeps the whole repo on ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_node_mesh(nodes: int = 1, devices_per_node: int = 0,
                    topo: Topology = None):
     """Hierarchical ``(node, device)`` mesh folded onto the host's devices.
@@ -152,7 +163,7 @@ def make_node_mesh(nodes: int = 1, devices_per_node: int = 0,
             f"virtual topology {nodes}x{devices_per_node} needs {need} "
             f"devices, host has {n_avail} (set "
             "--xla_force_host_platform_device_count before importing jax)")
-    return jax.make_mesh((nodes, devices_per_node), ("node", "device"))
+    return make_mesh((nodes, devices_per_node), ("node", "device"))
 
 
 def surviving_devices(mesh, lost_node: int):
@@ -217,7 +228,7 @@ def make_production_mesh(*, multi_pod: bool = False, data: int = 16,
     assert data * model == 256, (data, model)
     shape = (2, data, model) if multi_pod else (data, model)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dev_mesh(data: int = 1, model: int = 1):
@@ -225,7 +236,7 @@ def make_dev_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = min(model, max(n // data, 1))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 HARDWARE = {

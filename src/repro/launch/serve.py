@@ -24,16 +24,17 @@ import jax
 import numpy as np
 
 from repro.configs import base as config_base
+from repro.launch import compile_cache
 from repro.launch.mesh import make_dev_mesh
 from repro.models import api
 from repro.serve.engine import Request, ServeEngine
 
 
 def _resolve_pallas_routing(cfg, args):
-    """TPU-default kernel routing (satellite of the decode-kernel PR):
-    --pallas-attn/--pallas-ssm override, else REPRO_PALLAS_ATTN /
-    REPRO_PALLAS_SSM, else ON exactly on real TPUs.  Frozen into the
-    config here, so the decision is trace-time static."""
+    """Kernel routing: --pallas-attn/--pallas-ssm override, else
+    REPRO_PALLAS_ATTN / REPRO_PALLAS_SSM, else off
+    (`autotune.default_use_pallas`).  Frozen into the config here, so
+    the decision is trace-time static."""
     import dataclasses as _dc
 
     from repro.kernels import autotune as autotune_lib
@@ -172,6 +173,7 @@ def serve_gan(args):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", choices=("lm", "gan"), default="lm",
                     help="lm: continuous-batching decode; gan: 3DGAN "
@@ -189,13 +191,11 @@ def main():
     ap.add_argument("--pallas-attn", action=argparse.BooleanOptionalAction,
                     default=None,
                     help="route attention through the Pallas kernels "
-                         "(default: on on TPU, off elsewhere; env "
-                         "REPRO_PALLAS_ATTN overrides)")
+                         "(default: off; env REPRO_PALLAS_ATTN overrides)")
     ap.add_argument("--pallas-ssm", action=argparse.BooleanOptionalAction,
                     default=None,
                     help="route SSM scans through the Pallas kernels "
-                         "(default: on on TPU, off elsewhere; env "
-                         "REPRO_PALLAS_SSM overrides)")
+                         "(default: off; env REPRO_PALLAS_SSM overrides)")
     # gan route
     ap.add_argument("--ckpt", default="",
                     help="generator checkpoint dir (launch/train --ckpt)")

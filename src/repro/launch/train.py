@@ -29,6 +29,7 @@ import numpy as np
 from repro.configs import base as config_base
 from repro.data.calo import CaloSimulator, CaloSpec
 from repro.data.tokens import MarkovTokens
+from repro.launch import compile_cache
 from repro.launch.mesh import make_dev_mesh, make_node_mesh
 from repro.models import api
 from repro.optim import optimizers as opt_lib
@@ -148,7 +149,8 @@ def train_lm(args, mesh, log: MetricLog):
     return state.params
 
 
-def main():
+def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="calo3dgan",
                     choices=config_base.ARCH_IDS)
@@ -191,13 +193,13 @@ def main():
     ap.add_argument("--pallas-attn", action=argparse.BooleanOptionalAction,
                     default=None,
                     help="LM archs: route attention through the Pallas "
-                         "kernels (default: on on TPU, off elsewhere; env "
-                         "REPRO_PALLAS_ATTN overrides)")
+                         "kernels (default: off; env REPRO_PALLAS_ATTN "
+                         "overrides)")
     ap.add_argument("--pallas-ssm", action=argparse.BooleanOptionalAction,
                     default=None,
                     help="LM archs: route SSM scans through the Pallas "
-                         "kernels (default: on on TPU, off elsewhere; env "
-                         "REPRO_PALLAS_SSM overrides)")
+                         "kernels (default: off; env REPRO_PALLAS_SSM "
+                         "overrides)")
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--log", default="")
     ap.add_argument("--log-every", type=int, default=1,
@@ -206,7 +208,7 @@ def main():
     ap.add_argument("--sync-every", type=int, default=0,
                     help="force a device sync every N steps to bound "
                          "run-ahead (0: never)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.loop == "naive" and args.arch != "calo3dgan":
         ap.error("--loop naive is the GAN train_on_batch baseline; "
                  "LM archs support builtin/custom/fused")

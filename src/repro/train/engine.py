@@ -67,7 +67,6 @@ from typing import Any, Callable, Iterable, Iterator, Mapping, NamedTuple, Optio
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.data import pipeline
@@ -404,9 +403,9 @@ class Engine:
                 metrics = jax.lax.pmean(metrics, axes)
             return state, metrics
 
-        smapped = shard_map(local_step, mesh=self.mesh,
-                            in_specs=(s_specs, b_specs, P()),
-                            out_specs=(s_specs, P()), check_rep=False)
+        smapped = jax.shard_map(local_step, mesh=self.mesh,
+                                in_specs=(s_specs, b_specs, P()),
+                                out_specs=(s_specs, P()), check_vma=False)
         return jax.jit(smapped, in_shardings=(s_shard, b_shard, rep),
                        out_shardings=(s_shard, rep), donate_argnums=donate)
 

@@ -43,6 +43,30 @@ def test_generator_output_shape_and_nonnegative():
     assert (np.asarray(img) >= 0).all()          # softplus energies
 
 
+@pytest.mark.parametrize("stride", [1, 2])
+def test_single_channel_conv_matches_lax_conv(stride):
+    """The discriminator's first layer (1 input channel) is computed as
+    patches @ kernel; it must be the same conv, value and gradients."""
+    kx, kw = jax.random.split(jax.random.key(stride))
+    x = jax.random.normal(kx, (2, 11, 9, 7, 1))
+    w = jax.random.normal(kw, (3, 3, 3, 1, 4))
+
+    def ref(x, w):
+        return jax.lax.conv_general_dilated(x, w, (stride,) * 3, "SAME",
+                                            dimension_numbers=gan.DN)
+
+    def loss(f):
+        return lambda x, w: (f(x, w) ** 2).sum()
+
+    out = gan._conv_layer(x, w, stride=stride, pallas=False)
+    np.testing.assert_allclose(out, ref(x, w), rtol=1e-5, atol=1e-5)
+    got = jax.grad(loss(lambda x, w: gan._conv_layer(
+        x, w, stride=stride, pallas=False)), argnums=(0, 1))(x, w)
+    want = jax.grad(loss(ref), argnums=(0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+
+
 def test_generator_energy_conditioning():
     """Higher E_p must produce more total deposited energy (built-in
     response scaling — the physics prior the GAN starts from)."""

@@ -274,3 +274,17 @@ def test_gemm_bf16_accumulates_f32():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=0.15, rtol=0.05)
+
+
+@pytest.mark.parametrize("value,on", [(None, False), ("0", False),
+                                      ("off", False), ("1", True),
+                                      ("on", True)])
+def test_pallas_routing_default_off_unless_env_forces(monkeypatch, value, on):
+    """Launchers route attention/SSM to Pallas only when asked: the kernels
+    do not compile for v5e yet (tests/test_tpu_compile.py)."""
+    from repro.kernels import autotune
+    if value is None:
+        monkeypatch.delenv("REPRO_PALLAS_ATTN", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_PALLAS_ATTN", value)
+    assert autotune.default_use_pallas("REPRO_PALLAS_ATTN") is on

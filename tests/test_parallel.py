@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel import collectives, sharding
 from repro.parallel.jaxpr_cost import cost_of, jaxpr_cost
 
@@ -21,14 +22,14 @@ def _mesh2(data=2, model=1):
 
 
 def test_resolve_spec_basic():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = sharding.resolve_spec(("embed", "heads"), (64, 64), mesh,
                                  sharding.FSDP_TP_RULES)
     assert spec == P("data", "model")
 
 
 def test_resolve_spec_drops_non_dividing_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     # dim 3 % mesh size 1 == 0 always with size-1 axes; use synthetic rules
     rules = {"x": "data"}
     spec = sharding.resolve_spec(("x",), (3,), mesh, rules)
@@ -36,14 +37,14 @@ def test_resolve_spec_drops_non_dividing_axis():
 
 
 def test_resolve_spec_never_reuses_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = {"a": "model", "b": "model"}
     spec = sharding.resolve_spec(("a", "b"), (8, 8), mesh, rules)
     assert spec == P("model", None)     # second use dropped
 
 
 def test_resolve_spec_tuple_rule():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = {"batch": ("pod", "data")}      # pod not in mesh -> filtered
     spec = sharding.resolve_spec(("batch", None), (8, 4), mesh, rules)
     assert spec == P("data", None)
@@ -52,7 +53,7 @@ def test_resolve_spec_tuple_rule():
 def test_dp_rules_replicate_params():
     """Paper-faithful mirrored strategy: every param spec resolves to fully
     replicated under DP_RULES."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     spec = sharding.resolve_spec(("embed", "heads"), (64, 64), mesh,
                                  sharding.DP_RULES)
     assert spec == P(None, None)
@@ -61,7 +62,7 @@ def test_dp_rules_replicate_params():
 def test_tree_specs_all_leaves_covered():
     from repro.configs import base as config_base
     from repro.models import api
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for arch in ("qwen2-1.5b", "olmoe-1b-7b", "xlstm-125m", "zamba2-1.2b",
                  "whisper-base"):
         cfg = config_base.reduced_config(arch)

@@ -8,6 +8,7 @@ pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel import collectives, sharding
 from repro.substrate import attention as attn_lib
 from repro.substrate import layers
@@ -28,7 +29,7 @@ SETTINGS = dict(max_examples=8, deadline=None)
 def test_resolve_spec_invariants(dims, axis_names):
     """For ANY shape/logical-axis combination: every mesh axis appears at
     most once, and every sharded dim is divisible by its axis size."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     logical = tuple(axis_names[:len(dims)])
     spec = sharding.resolve_spec(logical, tuple(dims), mesh,
                                  sharding.FSDP_TP_RULES)
@@ -215,7 +216,7 @@ def test_checkpoint_roundtrip_random_pytrees(tmp_path_factory, leaves,
     dropping ANY leaf from the template raises naming its key path (the
     strict-restore contract)."""
     from repro.train import checkpoint as ckpt_lib
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rep = jax.sharding.NamedSharding(mesh, P())
     rng = np.random.default_rng(seed)
     tree = {}

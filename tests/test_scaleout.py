@@ -316,7 +316,6 @@ def test_grad_reduce_traffic_matches_param_bytes():
 
 
 def test_jaxpr_cost_counts_shard_map_psum_bytes():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_node_mesh(1, 1)
@@ -324,8 +323,8 @@ def test_jaxpr_cost_counts_shard_map_psum_bytes():
     def local(x):
         return jax.lax.psum(x, ("node", "device"))
 
-    fn = shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=P(), out_specs=P(),
+                       check_vma=False)
     stats = cost_of(fn, jax.ShapeDtypeStruct((256, 128), jnp.float32))
     # mesh.size (=1) * result bytes
     assert stats["collective_bytes"] == 256 * 128 * 4
@@ -335,7 +334,6 @@ def test_jaxpr_cost_per_kind_collective_bytes():
     """psum / all_gather / psum_scatter land in their own byte columns
     (what separates ZeRO's reduce-scatter + all-gather from plain
     all-reduce in the bench report)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_node_mesh(1, 1)
@@ -347,8 +345,8 @@ def test_jaxpr_cost_per_kind_collective_bytes():
                                  tiled=True)
         return a, g, s
 
-    fn = shard_map(local, mesh=mesh, in_specs=P(), out_specs=(P(), P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=P(),
+                       out_specs=(P(), P(), P()), check_vma=False)
     stats = cost_of(fn, jax.ShapeDtypeStruct((16, 8), jnp.float32))
     nb = 16 * 8 * 4
     assert stats["psum_bytes"] == nb
@@ -362,7 +360,6 @@ def test_collective_schedule_overlap_exposes_less():
     leave a strictly smaller byte-fraction of its collectives exposed
     (no independent later compute) than the post-backward hierarchical
     schedule, on the real custom-loop GAN step."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.parallel import jaxpr_cost
 
@@ -381,9 +378,9 @@ def test_collective_schedule_overlap_exposes_less():
                                               bucket_bytes=int(0.05 *
                                                                (1 << 20)))
         step = task.make_step(grad_reduce=reduce, mesh=None)
-        smapped = shard_map(step, mesh=mesh,
-                            in_specs=(P(), P(), P()),
-                            out_specs=(P(), P()), check_rep=False)
+        smapped = jax.shard_map(step, mesh=mesh,
+                                in_specs=(P(), P(), P()),
+                                out_specs=(P(), P()), check_vma=False)
         sched = jaxpr_cost.schedule_of(smapped, state, batch,
                                        jax.random.key(1))
         assert sched["n_collectives"] > 0
@@ -403,12 +400,11 @@ def test_custom_loop_collective_bytes_cover_grad_traffic():
     sim = CaloSimulator(CaloSpec(image_shape=GAN_CFG.image_shape), seed=0)
     batch = next(sim.batches(8))
     step = task.make_step(grad_reduce=eng._grad_reduce, mesh=None)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     state = eng.init_state(task, jax.random.key(0))
-    smapped = shard_map(step, mesh=mesh,
-                        in_specs=(P(), P(), P()), out_specs=(P(), P()),
-                        check_rep=False)
+    smapped = jax.shard_map(step, mesh=mesh,
+                            in_specs=(P(), P(), P()), out_specs=(P(), P()),
+                            check_vma=False)
     stats = cost_of(smapped, state, batch, jax.random.key(1))
     expect = adversarial.grad_reduce_traffic(GAN_CFG)["bytes_per_step"]
     assert stats["collective_bytes"] >= expect

@@ -113,6 +113,19 @@ def test_gan_build_lowers_on_dev_mesh():
         assert _cost(compiled).get("flops", 0) > 0
 
 
+def test_train_launcher_builtin_gan_runs_in_process(monkeypatch, capsys):
+    """`python -m repro.launch.train` with the default builtin loop: the
+    launcher's own mesh must accept the step's sharding constraints (a
+    mesh with Explicit axes made this raise before any step ran)."""
+    from repro.launch import compile_cache, train
+    monkeypatch.setattr(compile_cache, "enable", lambda: None)
+    train.main(["--arch", "calo3dgan", "--reduced", "--steps", "2",
+                "--loop", "builtin"])
+    out = capsys.readouterr().out
+    assert "[step      0" in out and "d_loss_real=" in out
+    assert "physics validation:" in out
+
+
 def test_ragged_engine_matches_single_request():
     """Per-slot vector positions: a request served alongside OTHER ragged
     requests must produce the same tokens as served alone."""

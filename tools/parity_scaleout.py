@@ -2,6 +2,7 @@
 import os
 import sys
 
+os.environ["JAX_PLATFORMS"] = "cpu"       # a virtual CPU pool, never a chip
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
                            + os.environ.get("XLA_FLAGS", ""))
 """2x2 virtual-topology parity: grad-reduce strategies + ZeRO-1 optimizer.

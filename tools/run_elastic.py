@@ -33,6 +33,7 @@ def parse_args(argv=None):
 
 
 ARGS = parse_args()
+os.environ["JAX_PLATFORMS"] = "cpu"       # a virtual CPU pool, never a chip
 os.environ["XLA_FLAGS"] = (
     f"--xla_force_host_platform_device_count={ARGS.devices} "
     + os.environ.get("XLA_FLAGS", ""))
