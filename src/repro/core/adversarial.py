@@ -31,6 +31,15 @@ from repro.core import gan
 from repro.optim import optimizers as opt_lib
 from repro.substrate import precision as precision_lib
 
+# Algorithm 1's phases, as ``jax.named_scope`` names inside the fused step:
+# every op of a phase carries its name in the compiled program's
+# ``op_name`` metadata, so a device trace can be split by phase.  Within a
+# phase, the gradient reduction and the optimizer update sit under the
+# nested scope ``UPDATE``; forward and backward ops are told apart by the
+# ``jvp(``/``transpose(jvp(`` markers JAX itself puts in ``op_name``.
+PHASES = ("d_real", "d_fake", "g")
+UPDATE = "update"
+
 
 def _freeze_pallas_conv(cfg):
     """Pin the Pallas fused-conv decision into the config at STEP
@@ -350,24 +359,27 @@ def make_fused_step(cfg, g_optimizer, d_optimizer, mesh=None, policy=None,
                 loss_fn = lambda p, x: base_loss(wrap_params(p), x)
             if ls is None:
                 l, aux, g = accum(loss_fn, params, xs)
-                upd, new_opt = optimizer.update(reduce_grads(g), opt_state,
-                                                params)
-                return (l, aux, opt_lib.apply_updates(params, upd), new_opt,
-                        None, jnp.float32(1.0))
+                with jax.named_scope(UPDATE):
+                    upd, new_opt = optimizer.update(reduce_grads(g),
+                                                    opt_state, params)
+                    new_params = opt_lib.apply_updates(params, upd)
+                return (l, aux, new_params, new_opt, None, jnp.float32(1.0))
 
             def scaled(p, x):
                 l_, aux_ = loss_fn(p, x)
                 return l_ * ls.scale, aux_
 
             l, aux, g = accum(scaled, params, xs)
-            g = reduce_grads(precision_lib.unscale(ls, g))
-            finite = precision_lib.all_finite(g)
-            upd, new_opt = optimizer.update(g, opt_state, params)
-            new_params = precision_lib.select_finite(
-                finite, opt_lib.apply_updates(params, upd), params)
-            new_opt = precision_lib.select_finite(finite, new_opt, opt_state)
-            ls2 = precision_lib.next_loss_scale(ls, finite,
-                                                policy.growth_interval)
+            with jax.named_scope(UPDATE):
+                g = reduce_grads(precision_lib.unscale(ls, g))
+                finite = precision_lib.all_finite(g)
+                upd, new_opt = optimizer.update(g, opt_state, params)
+                new_params = precision_lib.select_finite(
+                    finite, opt_lib.apply_updates(params, upd), params)
+                new_opt = precision_lib.select_finite(finite, new_opt,
+                                                      opt_state)
+                ls2 = precision_lib.next_loss_scale(ls, finite,
+                                                    policy.growth_interval)
             return (l / ls.scale, aux, new_params, new_opt, ls2,
                     finite.astype(jnp.float32))
 
@@ -378,8 +390,10 @@ def make_fused_step(cfg, g_optimizer, d_optimizer, mesh=None, policy=None,
             return gan.disc_loss(to_compute(dp), x["image"],
                                  (x["e_p"], x["theta"], x["ecal"]), cfg,
                                  real=True)
-        d_lr, d_mr, d_params, d_opt, ls, fin_r = phase(
-            d_loss_real, state.d_params, real, state.d_opt, d_optimizer, ls)
+        with jax.named_scope("d_real"):
+            d_lr, d_mr, d_params, d_opt, ls, fin_r = phase(
+                d_loss_real, state.d_params, real, state.d_opt, d_optimizer,
+                ls)
 
         # ---- D on fake (generation INSIDE the compiled program) -------
         def d_loss_fake(dp, k):
@@ -388,10 +402,9 @@ def make_fused_step(cfg, g_optimizer, d_optimizer, mesh=None, policy=None,
             return gan.disc_loss(to_compute(dp), jax.lax.stop_gradient(fake),
                                  (f_ep, f_th, f_ep * ecal_frac), cfg,
                                  real=False)
-        d_lf, d_mf, d_params, d_opt, ls, fin_f = phase(
-            d_loss_fake, d_params, d_keys, d_opt, d_optimizer, ls)
-
-        d_params_c = to_compute(d_params)         # G-phase D, nondiff
+        with jax.named_scope("d_fake"):
+            d_lf, d_mf, d_params, d_opt, ls, fin_f = phase(
+                d_loss_fake, d_params, d_keys, d_opt, d_optimizer, ls)
 
         # ---- G twice ---------------------------------------------------
         def one_g(carry, ks):
@@ -405,8 +418,10 @@ def make_fused_step(cfg, g_optimizer, d_optimizer, mesh=None, policy=None,
                 loss, g_params, ks, g_opt, g_optimizer, ls)
             return (g_params, g_opt, ls), (g_l, fin)
 
-        (g_params, g_opt, ls), (g_ls, g_fins) = jax.lax.scan(
-            one_g, (state.g_params, state.g_opt, ls), g_keys)
+        with jax.named_scope("g"):
+            d_params_c = to_compute(d_params)     # G-phase D, nondiff
+            (g_params, g_opt, ls), (g_ls, g_fins) = jax.lax.scan(
+                one_g, (state.g_params, state.g_opt, ls), g_keys)
 
         new = GANState(g_params, d_params, g_opt, d_opt, state.step + 1,
                        ls if scaling else state.loss_scale)

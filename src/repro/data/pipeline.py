@@ -15,7 +15,8 @@ equivalent implemented here:
   finished device arrays off a bounded queue; the time it spends BLOCKED
   on that queue is exactly the transfer/host time the overlap failed to
   hide, surfaced as ``Prefetcher.stats["h2d_wait_ms"]`` (the engine
-  re-exposes it per logging window in ``Engine.last_fit_stats``).
+  re-exposes it per logging window in ``Engine.last_fit_stats``) and, in
+  a profiler trace, as one ``repro.prefetch.wait`` host span per batch.
 """
 from __future__ import annotations
 
@@ -75,9 +76,9 @@ class Prefetcher:
 
     - ``h2d_wait_ms``  — total time the CONSUMER blocked waiting for a
       batch, i.e. transfer/host time compute did not hide (0 when the
-      pipeline keeps up);
-    - ``put_ms``       — producer time spent issuing ``device_put``
-      dispatches (not the transfer itself, which is async);
+      pipeline keeps up).  The same wait is a ``repro.prefetch.wait``
+      span (``jax.profiler.TraceAnnotation``) in a profiler trace, on the
+      clock of the device ops;
     - ``batches``      — batches yielded so far.
 
     Exceptions in the source iterator are re-raised to the consumer.
@@ -88,7 +89,7 @@ class Prefetcher:
     def __init__(self, it: Iterator[dict], size: int = 2, sharding=None):
         self._q: queue.Queue = queue.Queue(maxsize=max(int(size), 1))
         self._sharding = sharding
-        self.stats = {"h2d_wait_ms": 0.0, "put_ms": 0.0, "batches": 0}
+        self.stats = {"h2d_wait_ms": 0.0, "batches": 0}
         self._thread = threading.Thread(
             target=self._produce, args=(it,), daemon=True)
         self._thread.start()
@@ -102,10 +103,7 @@ class Prefetcher:
     def _produce(self, it):
         try:
             for batch in it:
-                t0 = time.perf_counter()
-                placed = self._place(batch)
-                self.stats["put_ms"] += 1e3 * (time.perf_counter() - t0)
-                self._q.put(placed)
+                self._q.put(self._place(batch))
         except BaseException as e:        # surface in the consumer
             self._q.put((self._DONE, e))
             return
@@ -115,9 +113,10 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        item = self._q.get()
-        self.stats["h2d_wait_ms"] += 1e3 * (time.perf_counter() - t0)
+        with jax.profiler.TraceAnnotation("repro.prefetch.wait"):
+            t0 = time.perf_counter()
+            item = self._q.get()
+            self.stats["h2d_wait_ms"] += 1e3 * (time.perf_counter() - t0)
         if isinstance(item, tuple) and len(item) == 2 \
                 and item[0] is self._DONE:
             self._q.put(item)             # keep raising on repeat next()

@@ -463,7 +463,8 @@ class Engine:
         the dispatch-count observability the async tests assert on, plus
         the per-window consumer stall of the device prefetcher (time a
         step had to WAIT for its batch; ~0 when the producer-side
-        ``device_put`` fully overlaps compute).
+        ``device_put`` fully overlaps compute; each wait is also a
+        ``repro.prefetch.wait`` span in a profiler trace).
         """
         if log_every < 1:
             raise ValueError(f"log_every must be >= 1, got {log_every}")
@@ -520,7 +521,6 @@ class Engine:
         self.last_fit_stats = {
             "steps": last + 1, "host_transfers": transfers,
             "h2d_wait_ms": stream.stats["h2d_wait_ms"],
-            "h2d_put_ms": stream.stats["put_ms"],
             "h2d_wait_ms_windows": h2d_windows,
         }
         return state, metrics
