@@ -1,7 +1,8 @@
 """3DGAN — three-dimensional convolutional ACGAN for calorimeter simulation.
 
 Generator: (latent ⊕ E_p ⊕ theta) -> dense -> stack of stride-2 3-D
-transposed convolutions -> crop -> softplus (energies are non-negative).
+transposed convolutions, the last emitting only the image's voxels ->
+softplus (energies are non-negative).
 
 Discriminator: stride-2 3-D convolutions -> heads:
   - validity logit (real/fake),
@@ -73,9 +74,14 @@ class use_pallas_conv:
 
 
 def _conv_layer(x, w, b=None, stride=1, *, activation="none", slope=0.2,
-                transpose=False, pallas=None):
+                transpose=False, pallas=None, out_dims=None):
     """One conv layer; on the Pallas path conv+bias+activation are ONE
-    fused kernel launch, on the lax path the same math is left to XLA."""
+    fused kernel launch, on the lax path the same math is left to XLA.
+
+    ``out_dims`` (transposed convs only): keep only the first ``out_dims``
+    of SAME's ``stride * n`` outputs per spatial axis.  The lax path never
+    computes the rest; the Pallas kernel has no window, so its output is
+    sliced right after the launch (bias and activation are elementwise)."""
     if pallas is None:
         pallas = pallas_conv_enabled()
     if pallas:
@@ -85,10 +91,16 @@ def _conv_layer(x, w, b=None, stride=1, *, activation="none", slope=0.2,
         bias = b if b is not None else jnp.zeros((w.shape[-1],), x.dtype)
         # w stays in param dtype: the kernel casts for compute, the custom
         # vjp hands back dw in param dtype (bf16 policy safe)
-        return op(x, w, bias, stride, activation, slope, None)
+        out = op(x, w, bias, stride, activation, slope, None)
+        if out_dims is not None:
+            out = out[:, :out_dims[0], :out_dims[1], :out_dims[2]]
+        return out
     if transpose:
+        pads = ("SAME" if out_dims is None else
+                [_transpose_padding(k, stride, n, m) for k, n, m
+                 in zip(w.shape[:3], x.shape[1:4], out_dims)])
         out = jax.lax.conv_transpose(x, w.astype(x.dtype), (stride,) * 3,
-                                     "SAME", dimension_numbers=DN)
+                                     pads, dimension_numbers=DN)
     elif x.shape[-1] == 1:
         out = _single_channel_conv(x, w, stride)
     else:
@@ -101,6 +113,17 @@ def _conv_layer(x, w, b=None, stride=1, *, activation="none", slope=0.2,
     elif activation == "softplus":
         out = jax.nn.softplus(out)
     return out
+
+
+def _transpose_padding(k: int, stride: int, n: int, m: int):
+    """(low, high) padding of a transposed conv (kernel ``k``, ``stride``,
+    ``n`` inputs) that gives the first ``m`` of SAME's ``stride * n``
+    outputs: SAME's pair, its high side cut by the overhang."""
+    over = stride * n - m
+    assert 0 <= over < stride * n, (n, m)
+    pad_len = k + stride - 2
+    lo = k - 1 if stride > k - 1 else -(-pad_len // 2)
+    return lo, pad_len - lo - over
 
 
 def _single_channel_conv(x, w, stride):
@@ -168,13 +191,15 @@ def generate(p, noise, e_p, theta, cfg):
     x = x.reshape(-1, *d0, chs[0])
     for i in range(ups):
         # bias folds into the kernel epilogue; the activation cannot (a
-        # layernorm sits between), so it stays outside
+        # layernorm sits between), so it stays outside.  The last layer
+        # emits only the image's voxels: the norm is per voxel and the
+        # activation elementwise, so cropping here equals cropping after
+        # them, without normalising the overhang it would throw away
         x = _conv_layer(x, p[f"up{i}"]["w"], p[f"up{i}"]["b"], 2,
-                        transpose=True, pallas=pallas)
+                        transpose=True, pallas=pallas,
+                        out_dims=cfg.image_shape if i == ups - 1 else None)
         x = layers.apply_norm(p[f"up{i}"]["gn"], x, "layernorm")
         x = jax.nn.leaky_relu(x, 0.2)
-    X, Y, Z = cfg.image_shape
-    x = x[:, :X, :Y, :Z]
     # softplus keeps cell energies non-negative (fused into the conv
     # epilogue on the Pallas path); scale with E_p so the generator does
     # not have to learn the dynamic range from scratch

@@ -3,6 +3,8 @@
 Integration tests: naive and fused loops agree where they share RNG-free
 math, a short fused training run improves the discriminator/physics
 metrics, and the physics validation utilities behave."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -217,3 +219,115 @@ def test_gan_generator_pallas_conv_path():
     with gan.use_pallas_conv():
         v, e, t = gan.discriminate(dp, ref, cfg)
     np.testing.assert_allclose(np.asarray(v), np.asarray(v_ref), atol=1e-3)
+
+
+# ---------------------------------------------------------------------------
+# the last transposed conv emits only the image's voxels
+# ---------------------------------------------------------------------------
+
+
+def _generate_crop_late(p, noise, e_p, theta, cfg):
+    """The generator as written before the crop moved: every upsampling
+    layer runs SAME over its whole grid, and the image is cut from the
+    last one after its layernorm and LeakyReLU."""
+    dn = ("NDHWC", "DHWIO", "NDHWC")
+    ups = len(cfg.gen_channels) - 1
+    d0 = [-(-d // 2 ** ups) for d in cfg.image_shape]
+    e_n = (e_p / 100.0)[:, None]
+    z = jnp.concatenate([noise, e_n, theta[:, None]], axis=-1)
+    x = jax.nn.leaky_relu(z @ p["fc"]["w"] + p["fc"]["b"], 0.2)
+    x = x.reshape(-1, *d0, cfg.gen_channels[0])
+    for i in range(ups):
+        q = p[f"up{i}"]
+        x = jax.lax.conv_transpose(x, q["w"], (2, 2, 2), "SAME",
+                                   dimension_numbers=dn) + q["b"]
+        mean = x.mean(-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        x = (x - mean) * jax.lax.rsqrt(var + 1e-5)
+        x = jax.nn.leaky_relu(x * q["gn"]["scale"] + q["gn"]["bias"], 0.2)
+    X, Y, Z = cfg.image_shape
+    x = x[:, :X, :Y, :Z]
+    x = jax.lax.conv_general_dilated(x, p["out"]["w"], (1, 1, 1), "SAME",
+                                     dimension_numbers=dn) + p["out"]["b"]
+    return jax.nn.softplus(x) * (e_n[:, None, None, None] * 0.025)
+
+
+@pytest.mark.parametrize("cfg", [
+    calo3dgan.config(),                                   # 56x56x32 -> 51x51x25
+    calo3dgan.reduced(),                                  # 14^3 -> 13^3
+    calo3dgan.bench(),                                    # 10^3 -> 9^3
+    dataclasses.replace(calo3dgan.bench(), image_shape=(8, 8, 8)),  # no overhang
+], ids=["full", "reduced", "bench", "no_overhang"])
+def test_generator_crops_before_norm_matches_crop_late(cfg):
+    """Cropping inside the last transposed conv (negative high padding)
+    gives the values and the parameter gradients of cropping after its
+    layernorm and LeakyReLU, on the lax path, in f32."""
+    cfg = dataclasses.replace(cfg, use_pallas_conv=False)
+    p = gan.init_generator(jax.random.key(0), cfg)
+    # nonzero biases and norm affines, so every parameter takes part
+    leaves, tree = jax.tree_util.tree_flatten(p)
+    keys = jax.random.split(jax.random.key(1), len(leaves))
+    p = jax.tree_util.tree_unflatten(tree, [
+        v + 0.05 * jax.random.normal(k, v.shape) for v, k in zip(leaves, keys)])
+    noise = jax.random.normal(jax.random.key(2), (2, cfg.latent_dim))
+    e_p = jnp.array([80.0, 300.0])
+    theta = jnp.array([1.2, 1.9])
+    probe = jax.random.normal(jax.random.key(3), (2, *cfg.image_shape, 1))
+
+    def scalar(f):
+        return lambda p: (f(p, noise, e_p, theta, cfg) * probe).sum()
+
+    got = jax.jit(gan.generate, static_argnums=4)(p, noise, e_p, theta, cfg)
+    want = jax.jit(_generate_crop_late, static_argnums=4)(
+        p, noise, e_p, theta, cfg)
+    assert got.shape == (2, *cfg.image_shape, 1)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    g_got = jax.jit(jax.grad(scalar(gan.generate)))(p)
+    g_want = jax.jit(jax.grad(scalar(_generate_crop_late)))(p)
+    # the last layer's bias and norm gradients sum over the kept voxels in
+    # another order than over the whole grid (f32 reassociation: 2.3e-6 of
+    # the leaf's norm at the full config); every other leaf is bit-equal
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_got),
+                            jax.tree_util.tree_leaves(g_want)):
+        err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+        assert err < 1e-5, (jax.tree_util.keystr(path), err)
+
+
+def test_generator_pallas_path_crops_after_kernel():
+    """With an overhang (a 7^3 image from an 8^3 grid) the Pallas route,
+    which slices the kernel's output before the norm, gives the lax
+    route's image (interpret mode, tiny config)."""
+    cfg = dataclasses.replace(calo3dgan.bench(), image_shape=(7, 7, 7),
+                              gen_channels=(8, 4), disc_channels=(4, 8),
+                              latent_dim=16)
+    p = gan.init_generator(jax.random.key(0), cfg)
+    noise = jax.random.normal(jax.random.key(1), (2, cfg.latent_dim))
+    e_p = jnp.array([100.0, 300.0])
+    th = jnp.full((2,), jnp.pi / 2)
+    ref = gan.generate(p, noise, e_p, th,
+                       dataclasses.replace(cfg, use_pallas_conv=False))
+    out = gan.generate(p, noise, e_p, th,
+                       dataclasses.replace(cfg, use_pallas_conv=True))
+    assert out.shape == (2, 7, 7, 7, 1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-3, rtol=1e-3)
+
+
+def test_fused_step_never_builds_the_uncropped_grid():
+    """The full-size fused step, lowered (no compile): the last upsampling
+    layer's 56x56x32 grid appears nowhere, its 51x51x25x8 output does."""
+    from repro.substrate.precision import get_policy
+    cfg = calo3dgan.config()
+    g_opt, d_opt = opt_lib.rmsprop(1e-4), opt_lib.rmsprop(1e-4)
+    policy = get_policy(cfg.precision)
+    state = jax.eval_shape(lambda k: adversarial.init_state(
+        k, cfg, g_opt, d_opt, policy), jax.random.key(0))
+    B, (X, Y, Z) = cfg.batch_size, cfg.image_shape
+    batch = {"image": jax.ShapeDtypeStruct((B, X, Y, Z, 1), jnp.float32),
+             "e_p": jax.ShapeDtypeStruct((B,), jnp.float32),
+             "theta": jax.ShapeDtypeStruct((B,), jnp.float32),
+             "ecal": jax.ShapeDtypeStruct((B,), jnp.float32)}
+    step = adversarial.make_fused_step(cfg, g_opt, d_opt, policy=policy)
+    text = jax.jit(step).lower(state, batch, jax.random.key(1)).as_text()
+    assert "x56x56x32x" not in text
+    assert f"{B}x51x51x25x8x" in text
