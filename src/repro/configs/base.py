@@ -17,12 +17,47 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``n_experts`` counts the experts whose weights this device holds.
+
+    With ``dispatch="grouped"`` (DeepSeek-V3 routing, arXiv:2412.19437:
+    sigmoid scores, top-k of score + correction bias, normalised gates)
+    the router scores ``router_experts`` experts (0: ``n_experts``), this
+    device holds experts ``[expert_offset, expert_offset + n_experts)``
+    of them and returns only their part of the layer plus the shared
+    experts; no token is dropped.  ``dispatch="capacity"`` is the
+    GShard-style softmax router with one-hot capacity dispatch that holds
+    every expert and drops tokens past ``capacity_factor``."""
     n_experts: int
     top_k: int
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    dispatch: str = "capacity"       # capacity | grouped
+    router_experts: int = 0          # grouped: experts the router scores
+    expert_offset: int = 0           # grouped: first expert held here
+    n_shared_experts: int = 0        # grouped: one SwiGLU of n * d_ff_expert
+    routed_scale: float = 1.0        # grouped: routed_scaling_factor
+    bias_update_rate: float = 0.0    # grouped: gamma of the correction bias
+    seq_balance_loss: float = 0.0    # grouped: alpha of the sequence-wise loss
+
+    @property
+    def n_router(self) -> int:
+        return self.router_experts or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), with
+    the query uncompressed (q = h W_q)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +99,10 @@ class ArchConfig:
     # family extensions
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    mla: Optional[MLAConfig] = None
+    # leading layers that keep a dense FFN of width d_ff before the MoE
+    # layers (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
 
     # enc-dec (audio)
     is_encoder_decoder: bool = False
@@ -105,7 +144,10 @@ class ArchConfig:
         n = c.vocab * c.d_model                       # embedding
         if not self.tie_embeddings:
             n += c.vocab * c.d_model                  # lm head
-        n += _block_params(c) * c.n_layers
+        if c.first_k_dense:
+            dense = dataclasses.replace(c, moe=None)
+            n += _block_params(dense) * c.first_k_dense
+        n += _block_params(c) * (c.n_layers - c.first_k_dense)
         if c.is_encoder_decoder:
             n += _block_params(c, cross=False) * c.n_encoder_layers
         return n
@@ -117,19 +159,29 @@ class ArchConfig:
         c, m = self, self.moe
         dense = self.param_count()
         expert_p = 3 * c.d_model * m.d_ff_expert
-        inactive = (m.n_experts - m.top_k) * expert_p * c.n_layers
+        inactive = (m.n_experts - m.top_k) * expert_p * (
+            c.n_layers - c.first_k_dense)
         return dense - inactive
 
 
 def _block_params(c: ArchConfig, cross: bool = False) -> int:
     """Approximate per-block parameter count."""
     attn = c.d_model * c.q_dim + 2 * c.d_model * c.kv_dim + c.q_dim * c.d_model
+    if c.mla is not None:
+        a = c.mla
+        attn = (c.d_model * c.n_heads * a.qk_head_dim
+                + c.d_model * (a.kv_lora_rank + a.qk_rope_head_dim)
+                + a.kv_lora_rank
+                + a.kv_lora_rank * c.n_heads * (a.qk_nope_head_dim
+                                                + a.v_head_dim)
+                + c.n_heads * a.v_head_dim * c.d_model)
     if c.family == "ssm":
         d_in = (c.ssm.expand if c.ssm else 2) * c.d_model
         return 2 * (c.d_model * 2 * d_in)           # rough: mlstm/slstm proj
     if c.moe is not None:
         ffn = c.moe.n_experts * 3 * c.d_model * c.moe.d_ff_expert
-        ffn += c.d_model * c.moe.n_experts          # router
+        ffn += c.d_model * c.moe.n_router           # router
+        ffn += c.moe.n_shared_experts * 3 * c.d_model * c.moe.d_ff_expert
     elif c.ffn_type == "swiglu":
         ffn = 3 * c.d_model * c.d_ff
     else:
@@ -158,6 +210,7 @@ ARCH_IDS = (
     "xlstm-125m",
     "qwen2-1.5b",
     "phi4-mini-3.8b",
+    "moonlight-16b-a3b",
     "calo3dgan",                 # the paper's own architecture
 )
 

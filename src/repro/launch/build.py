@@ -96,7 +96,9 @@ def build_train(arch_id: str, shape_name: str, mesh: Mesh, *,
     optimizer = opt_lib.get_optimizer(optimizer_name, lr)
 
     p_shard, p_shapes = param_shardings(model, cfg, mesh, rules)
-    o_shard, o_shapes = opt_state_shardings(optimizer, p_shapes, p_shard, mesh)
+    o_shard, o_shapes = opt_state_shardings(
+        optimizer, steps_lib.trainable(p_shapes),
+        steps_lib.trainable(p_shard), mesh)
     b_shapes = api.train_batch_specs(cfg, shape)
     b_shard = batch_shardings(b_shapes, mesh)
 

@@ -34,6 +34,9 @@ class Model(NamedTuple):
     # that can ingest a prompt chunk in one launch); None -> the engine
     # falls back to sequential token-by-token prefill
     prefill_chunk: Optional[Callable] = None
+    # ``update_buffers(buffers, metrics, cfg)``: the after-step update of
+    # state kept outside the optimizer (``train/steps.BUFFERS``)
+    update_buffers: Optional[Callable] = None
 
 
 def get_model(cfg: ArchConfig) -> Model:
@@ -49,7 +52,8 @@ def get_model(cfg: ArchConfig) -> Model:
         raise ValueError(cfg.family)
     return Model(m.init, m.logical_axes, m.loss_fn, m.init_cache,
                  m.cache_logical_axes, m.prefill, m.decode_step,
-                 getattr(m, "prefill_chunk", None))
+                 getattr(m, "prefill_chunk", None),
+                 getattr(m, "update_buffers", None))
 
 
 # ---------------------------------------------------------------------------
@@ -114,6 +118,8 @@ def decode_supported(cfg: ArchConfig, shape: InputShape) -> bool:
     """Which (arch, shape) decode pairs exist (DESIGN.md shape notes)."""
     if shape.kind != "decode":
         return True
+    if not cfg.decode_supported:
+        return False
     if shape.name == "long_500k" and cfg.family == "audio":
         return False            # whisper: no 500k decode (DESIGN.md skip)
     return True

@@ -12,6 +12,7 @@ The LM head + cross-entropy are computed in sequence chunks so the full
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -27,14 +28,26 @@ from repro.substrate import layers, moe as moe_lib
 # ---------------------------------------------------------------------------
 
 
+def _grouped(cfg) -> bool:
+    return cfg.moe is not None and cfg.moe.dispatch == "grouped"
+
+
+def _dense_cfg(cfg):
+    """The leading dense layers' config: the same block with a dense FFN."""
+    return dataclasses.replace(cfg, moe=None)
+
+
 def init_block(key, cfg):
     ks = jax.random.split(key, 3)
     p = {
         "ln1": layers.init_norm(cfg.d_model, cfg.norm_type),
-        "attn": attn_lib.init_attn(ks[0], cfg),
+        "attn": (attn_lib.init_mla(ks[0], cfg) if cfg.mla is not None
+                 else attn_lib.init_attn(ks[0], cfg)),
         "ln2": layers.init_norm(cfg.d_model, cfg.norm_type),
     }
-    if cfg.moe is not None:
+    if _grouped(cfg):
+        p["moe"] = moe_lib.init_grouped(ks[1], cfg)
+    elif cfg.moe is not None:
         p["moe"] = moe_lib.init_moe(ks[1], cfg)
     else:
         p["ffn"] = layers.init_ffn(ks[1], cfg.d_model, cfg.d_ff, cfg.ffn_type)
@@ -44,10 +57,13 @@ def init_block(key, cfg):
 def block_axes(cfg):
     p = {
         "ln1": layers.norm_axes(cfg.norm_type),
-        "attn": attn_lib.attn_axes(cfg),
+        "attn": (attn_lib.mla_axes(cfg) if cfg.mla is not None
+                 else attn_lib.attn_axes(cfg)),
         "ln2": layers.norm_axes(cfg.norm_type),
     }
-    if cfg.moe is not None:
+    if _grouped(cfg):
+        p["moe"] = moe_lib.grouped_axes(cfg)
+    elif cfg.moe is not None:
         p["moe"] = moe_lib.moe_axes(cfg)
     else:
         p["ffn"] = layers.ffn_axes(cfg.ffn_type)
@@ -57,6 +73,21 @@ def block_axes(cfg):
 def _attend(q, k, v, *, causal, window, seq_len, use_pallas=False):
     return attn_lib.attend(q, k, v, causal=causal, window=window,
                            use_pallas=use_pallas, seq_len=seq_len)
+
+
+def _apply_mla_block(p, x, cos, sin, cfg, bias):
+    """Latent-attention block (DeepSeek-V2/V3); x: (B, S, d) ->
+    (x', aux, stats), stats empty in a dense layer."""
+    h = layers.apply_norm(p["ln1"], x, cfg.norm_type)
+    x = x + attn_lib.apply_mla(p["attn"], h, cos, sin, cfg)
+    h = layers.apply_norm(p["ln2"], x, cfg.norm_type)
+    if cfg.moe is not None:
+        f, aux, stats = moe_lib.apply_grouped(p["moe"], h, bias, cfg)
+    else:
+        with jax.named_scope("dense_ffn"):
+            f = layers.apply_ffn(p["ffn"], h, cfg.ffn_type)
+        aux, stats = jnp.zeros((), jnp.float32), {}
+    return x + f, aux, stats
 
 
 def apply_block(p, x, cos, sin, cfg, *, window=0, mesh=None):
@@ -104,15 +135,29 @@ def apply_block(p, x, cos, sin, cfg, *, window=0, mesh=None):
 
 
 def init(rng, cfg):
+    """Params: ``blocks`` stacks the layers after the leading dense ones,
+    ``dense_blocks`` stacks those (``first_k_dense``), and a grouped-MoE
+    model keeps its routers' correction biases under ``buffers`` — state
+    that the train step updates outside the optimizer
+    (``train/steps.BUFFERS``)."""
     k_emb, k_blocks, k_head = jax.random.split(rng, 3)
-    block_keys = jax.random.split(k_blocks, cfg.n_layers)
+    block_keys = jax.random.split(k_blocks, cfg.n_layers - cfg.first_k_dense)
     p = {
         "embed": layers.init_embed(k_emb, cfg.vocab, cfg.d_model),
         "blocks": jax.vmap(lambda k: init_block(k, cfg))(block_keys),
         "ln_f": layers.init_norm(cfg.d_model, cfg.norm_type),
     }
+    if cfg.first_k_dense:
+        dense = _dense_cfg(cfg)
+        p["dense_blocks"] = jax.vmap(lambda k: init_block(k, dense))(
+            jax.random.split(jax.random.fold_in(k_blocks, 1),
+                             cfg.first_k_dense))
     if not cfg.tie_embeddings:
         p["head"] = {"w": layers.normal_init(k_head, (cfg.d_model, cfg.vocab))}
+    if _grouped(cfg):
+        p["buffers"] = {"router_bias": jnp.zeros(
+            (cfg.n_layers - cfg.first_k_dense, cfg.moe.n_router),
+            jnp.float32)}
     return p
 
 
@@ -122,9 +167,21 @@ def logical_axes(cfg):
         "blocks": sharding.stacked(block_axes(cfg)),
         "ln_f": layers.norm_axes(cfg.norm_type),
     }
+    if cfg.first_k_dense:
+        p["dense_blocks"] = sharding.stacked(block_axes(_dense_cfg(cfg)))
     if not cfg.tie_embeddings:
         p["head"] = {"w": ("embed", "vocab")}
+    if _grouped(cfg):
+        p["buffers"] = {"router_bias": (None, None)}
     return p
+
+
+def update_buffers(buffers, metrics, cfg):
+    """After a step: each router's correction bias moves toward balance
+    by the tokens each expert got in that step (DeepSeek-V3)."""
+    return {"router_bias": moe_lib.update_bias(
+        buffers["router_bias"], metrics["moe_tokens_per_expert"],
+        cfg.moe.bias_update_rate)}
 
 
 def _rope_for(cfg, positions, dtype):
@@ -133,12 +190,42 @@ def _rope_for(cfg, positions, dtype):
     if cfg.mrope:
         return attn_lib.mrope_cos_sin(positions, cfg.d_head, cfg.rope_theta,
                                       cfg.mrope_sections, dtype)
-    return attn_lib.rope_cos_sin(positions, cfg.d_head, cfg.rope_theta, dtype)
+    dim = cfg.mla.qk_rope_head_dim if cfg.mla is not None else cfg.d_head
+    return attn_lib.rope_cos_sin(positions, dim, cfg.rope_theta, dtype)
+
+
+def _mla_stack(blocks, x, cos, sin, cfg, bias, remat):
+    """Scan a stack of latent-attention blocks; per-layer MoE stats are
+    stacked on a leading layer axis."""
+    def body(carry, xs):
+        h, aux = carry
+        block_p, b = xs
+        h, a, stats = _apply_mla_block(block_p, h, cos, sin, cfg, b)
+        return (h, aux + a), stats
+
+    if remat:
+        body = jax.checkpoint(body)
+    n = jax.tree.leaves(blocks)[0].shape[0]
+    if bias is None:
+        bias = jnp.zeros((n, 0), jnp.float32)
+    (x, aux), stats = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   (blocks, bias))
+    return x, aux, stats
 
 
 def backbone(params, x, cfg, *, positions, mesh=None, remat=True, window=0):
-    """x: (B, S, d) embedded input -> (hidden (B,S,d), aux)."""
+    """x: (B, S, d) embedded input -> (hidden (B,S,d), aux, stats)."""
     cos, sin = _rope_for(cfg, positions, x.dtype)
+    if cfg.mla is not None:
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.first_k_dense:
+            x, aux, _ = _mla_stack(params["dense_blocks"], x, cos, sin,
+                                   _dense_cfg(cfg), None, remat)
+        bias = params.get("buffers", {}).get("router_bias")
+        x, a, stats = _mla_stack(params["blocks"], x, cos, sin, cfg, bias,
+                                 remat)
+        x = layers.apply_norm(params["ln_f"], x, cfg.norm_type)
+        return x, aux + a, stats
 
     def body(carry, block_p):
         h, aux = carry
@@ -150,7 +237,7 @@ def backbone(params, x, cfg, *, positions, mesh=None, remat=True, window=0):
     (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                params["blocks"])
     x = layers.apply_norm(params["ln_f"], x, cfg.norm_type)
-    return x, aux
+    return x, aux, {}
 
 
 def _head_matrix(params, cfg):
@@ -163,6 +250,8 @@ def forward(params, tokens, cfg, *, policy, positions=None, embeds=None,
             mesh=None, remat=True, window=0):
     """Returns final hidden states (NOT logits — see chunked loss)."""
     cparams = policy.cast_to_compute(params)
+    if "buffers" in params:     # routing state stays in float32
+        cparams = dict(cparams, buffers=params["buffers"])
     if embeds is not None:
         x = embeds.astype(policy.compute_dtype)
         if tokens is not None:      # VLM: patch embeds replace a token prefix
@@ -176,9 +265,9 @@ def forward(params, tokens, cfg, *, policy, positions=None, embeds=None,
         pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
         positions = jnp.broadcast_to(pos[None], (3, B, S)) if cfg.mrope else pos
     x = sharding.constrain_batch(x, mesh, seq_dim=1)
-    h, aux = backbone(cparams, x, cfg, positions=positions, mesh=mesh,
-                      remat=remat, window=window)
-    return h, aux, cparams
+    h, aux, stats = backbone(cparams, x, cfg, positions=positions, mesh=mesh,
+                             remat=remat, window=window)
+    return h, aux, stats, cparams
 
 
 def chunked_softmax_xent(h, head_w, targets, valid, chunk=512):
@@ -190,6 +279,8 @@ def chunked_softmax_xent(h, head_w, targets, valid, chunk=512):
     chunk = min(chunk, S)
     n = S // chunk
 
+    # rematerialised: a backward pass holds one chunk's logits, not all
+    @jax.checkpoint
     def body(carry, i):
         loss_sum, cnt = carry
         hs = jax.lax.dynamic_slice_in_dim(h, i * chunk, chunk, axis=1)
@@ -225,6 +316,9 @@ def init_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
     """KV cache pytree. For sliding-window serving, max_len = window and the
     cache is a ring buffer (rope is applied to k at write time, so ring order
     does not matter)."""
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            f"{cfg.arch_id}: latent attention has no KV cache for serving")
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.d_head)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -420,9 +514,9 @@ def loss_fn(params, batch, cfg, *, policy, mesh=None, remat=True):
     tokens = batch["tokens"]
     positions = batch.get("positions")
     embeds = batch.get("embeds")
-    h, aux, cparams = forward(params, tokens, cfg, policy=policy,
-                              positions=positions, embeds=embeds,
-                              mesh=mesh, remat=remat)
+    h, aux, stats, cparams = forward(params, tokens, cfg, policy=policy,
+                                     positions=positions, embeds=embeds,
+                                     mesh=mesh, remat=remat)
     # next-token prediction over the token region (embeds prefix has no labels)
     if embeds is not None:
         h = h[:, embeds.shape[1]:]
@@ -430,5 +524,6 @@ def loss_fn(params, batch, cfg, *, policy, mesh=None, remat=True):
     hh = h[:, :-1]
     valid = jnp.ones_like(targets, jnp.float32)
     head_w = _head_matrix(cparams, cfg)
-    ce = chunked_softmax_xent(hh, head_w, targets, valid)
-    return ce + aux, {"ce": ce, "aux": aux}
+    with jax.named_scope("lm_head"):
+        ce = chunked_softmax_xent(hh, head_w, targets, valid)
+    return ce + aux, dict(stats, ce=ce, aux=aux)

@@ -123,15 +123,19 @@ def _attend_block(q, k, v, q_off, k_off, causal, window, scale):
 
 
 def blockwise_attention(q, k, v, *, causal=True, window=0,
-                        q_chunk=512, kv_chunk=512, q_offset=0):
+                        q_chunk=512, kv_chunk=512, q_offset=0,
+                        remat_blocks=False):
     """Memory-bounded attention with online softmax.
 
     q: (B, S, H, D); k/v: (B, T, KH, D). GQA via head grouping.
     Python loop over q blocks (static causal kv extent -> exact FLOPs),
-    lax.scan over kv blocks (O(1) HLO in T).
+    lax.scan over kv blocks (O(1) HLO in T).  ``remat_blocks``
+    rematerialises each q block, so a backward pass holds one block's
+    scores at a time instead of every block's.
     """
     B, S, H, D = q.shape
     T, KH = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]            # latent attention: v heads narrower than q/k
     G = H // KH
     scale = 1.0 / (D ** 0.5)
     q = q.reshape(B, S, KH, G, D)
@@ -160,33 +164,41 @@ def blockwise_attention(q, k, v, *, causal=True, window=0,
             k_lo = 0
         n_kv = max(1, -(-(k_hi - k_lo) // kv_chunk))
 
-        def body(carry, ki, qb=qb, q_off=q_off, k_lo=k_lo):
-            acc, m, l = carry
-            k_off = k_lo + ki * kv_chunk
-            kb = jax.lax.dynamic_slice_in_dim(k, k_off, kv_chunk, axis=1)
-            vb = jax.lax.dynamic_slice_in_dim(v, k_off, kv_chunk, axis=1)
-            # mask out positions past T (dynamic_slice clamps, so re-mask)
-            kpos = k_off + jnp.arange(kv_chunk)
-            valid = kpos < T
-            o_b, m_b, l_b = _attend_block(
-                qb, jnp.where(valid[None, :, None, None], kb, 0),
-                jnp.where(valid[None, :, None, None], vb, 0),
-                q_off, k_off, causal, window, scale)
-            l_b = jnp.where(valid.any(), l_b, 0.0)
-            m_new = jnp.maximum(m, m_b)
-            a1 = jnp.exp(m - m_new)
-            a2 = jnp.exp(m_b - m_new)
-            acc = acc * a1[..., None] + o_b.transpose(0, 2, 3, 1, 4) * a2[..., None]
-            l = l * a1 + l_b * a2
-            return (acc, m_new, l), None
+        def q_block(qb, k, v, q_off=q_off, k_lo=k_lo, qlen=qlen,
+                    n_kv=n_kv):
+            def body(carry, ki):
+                acc, m, l = carry
+                k_off = k_lo + ki * kv_chunk
+                kb = jax.lax.dynamic_slice_in_dim(k, k_off, kv_chunk, axis=1)
+                vb = jax.lax.dynamic_slice_in_dim(v, k_off, kv_chunk, axis=1)
+                # mask out positions past T (dynamic_slice clamps, so re-mask)
+                kpos = k_off + jnp.arange(kv_chunk)
+                valid = kpos < T
+                o_b, m_b, l_b = _attend_block(
+                    qb, jnp.where(valid[None, :, None, None], kb, 0),
+                    jnp.where(valid[None, :, None, None], vb, 0),
+                    q_off, k_off, causal, window, scale)
+                l_b = jnp.where(valid.any(), l_b, 0.0)
+                m_new = jnp.maximum(m, m_b)
+                a1 = jnp.exp(m - m_new)
+                a2 = jnp.exp(m_b - m_new)
+                acc = (acc * a1[..., None]
+                       + o_b.transpose(0, 2, 3, 1, 4) * a2[..., None])
+                l = l * a1 + l_b * a2
+                return (acc, m_new, l), None
 
-        acc0 = jnp.zeros((B, KH, G, qlen, D), jnp.float32)
-        # m must start finite for exp(m - m_new); use large negative, not -inf
-        m0 = jnp.full((B, KH, G, qlen), -1e30)
-        l0 = jnp.zeros((B, KH, G, qlen), jnp.float32)
-        (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0), jnp.arange(n_kv))
-        o = acc / jnp.maximum(l[..., None], 1e-30)
-        outs.append(o.transpose(0, 3, 1, 2, 4).reshape(B, qlen, H, D))
+            acc0 = jnp.zeros((B, KH, G, qlen, Dv), jnp.float32)
+            # m must start finite for exp(m - m_new): large negative, not -inf
+            m0 = jnp.full((B, KH, G, qlen), -1e30)
+            l0 = jnp.zeros((B, KH, G, qlen), jnp.float32)
+            (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
+                                          jnp.arange(n_kv))
+            return acc / jnp.maximum(l[..., None], 1e-30)
+
+        if remat_blocks:
+            q_block = jax.checkpoint(q_block)
+        o = q_block(qb, k, v)
+        outs.append(o.transpose(0, 3, 1, 2, 4).reshape(B, qlen, H, Dv))
     return jnp.concatenate(outs, axis=1).astype(v.dtype) if len(outs) > 1 \
         else outs[0].astype(v.dtype)
 
@@ -257,4 +269,69 @@ def dot_attention(q, k, v, *, causal=True, window=0, kv_len=None, q_positions=No
     s = jnp.where(mask[:, None, None], s, -1e30)
     w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     o = jnp.einsum("bkgqt,btkd->bqkgd", w, v, preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, D).astype(v.dtype)
+    return o.reshape(B, S, H, v.shape[-1]).astype(v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434), no q-LoRA
+# ---------------------------------------------------------------------------
+
+
+def init_mla(key, cfg):
+    a, d, H = cfg.mla, cfg.d_model, cfg.n_heads
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": layers.init_dense(ks[0], d, H * a.qk_head_dim),
+        "wkv_a": layers.init_dense(ks[1], d,
+                                   a.kv_lora_rank + a.qk_rope_head_dim),
+        "kv_norm": layers.init_norm(a.kv_lora_rank),
+        "wkv_b": layers.init_dense(
+            ks[2], a.kv_lora_rank, H * (a.qk_nope_head_dim + a.v_head_dim)),
+        "wo": layers.init_dense(ks[3], H * a.v_head_dim, d,
+                                scale=0.02 / max(cfg.n_layers, 1) ** 0.5),
+    }
+
+
+def mla_axes(cfg):
+    return {
+        "wq": layers.dense_axes("embed", "heads"),
+        "wkv_a": layers.dense_axes("embed", None),
+        "kv_norm": layers.norm_axes(),
+        "wkv_b": layers.dense_axes(None, "heads"),
+        "wo": layers.dense_axes("heads", "embed"),
+    }
+
+
+def apply_mla(p, x, cos, sin, cfg):
+    """x: (B, S, d) -> (B, S, d), causal.
+
+    q = x W_q splits per head into q_nope and q_pe; [c_kv, k_pe] =
+    x W_kv_a with c_kv RMS-normed; [k_nope, v] per head = c_kv W_kv_b.
+    RoPE (``cos``/``sin`` of width qk_rope_head_dim / 2) turns q_pe and
+    the one k_pe that every head shares.  Scores are
+    (q_nope.k_nope + q_pe.k_pe) / sqrt(qk_head_dim).  RoPE here rotates
+    the two halves of the rope dims (``apply_rope``) where DeepSeek's code
+    rotates interleaved pairs: the same map up to a fixed permutation of
+    W_q's and W_kv_a's rope columns."""
+    a, H = cfg.mla, cfg.n_heads
+    B, S, _ = x.shape
+    with jax.named_scope("mla"):
+        q = layers.apply_dense(p["wq"], x).reshape(B, S, H, a.qk_head_dim)
+        q_nope, q_pe = jnp.split(q, [a.qk_nope_head_dim], axis=-1)
+        kv_a = layers.apply_dense(p["wkv_a"], x)
+        c_kv, k_pe = jnp.split(kv_a, [a.kv_lora_rank], axis=-1)
+        c_kv = layers.apply_norm(p["kv_norm"], c_kv)
+        kv = layers.apply_dense(p["wkv_b"], c_kv).reshape(
+            B, S, H, a.qk_nope_head_dim + a.v_head_dim)
+        k_nope, v = jnp.split(kv, [a.qk_nope_head_dim], axis=-1)
+        q_pe = apply_rope(q_pe, cos, sin)
+        k_pe = apply_rope(k_pe[:, :, None, :], cos, sin)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (B, S, H, a.qk_rope_head_dim))],
+            axis=-1)
+        if S <= 1024:
+            o = dot_attention(q, k, v, causal=True)
+        else:
+            o = blockwise_attention(q, k, v, causal=True, remat_blocks=True)
+        return layers.apply_dense(p["wo"], o.reshape(B, S, H * a.v_head_dim))

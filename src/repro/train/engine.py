@@ -147,7 +147,7 @@ def lm_task(model, cfg, optimizer, *, policy, microbatches: int = 1,
 
     def init(rng):
         params = model.init(rng, cfg)
-        return LMState(params, optimizer.init(params))
+        return LMState(params, optimizer.init(steps_lib.trainable(params)))
 
     def make_step(grad_reduce=None, mesh=None):
         inner = steps_lib.make_train_step(

@@ -32,8 +32,10 @@ class MetricAccumulator:
 
     @staticmethod
     def _f32(metrics) -> dict:
+        """The step's scalars; array counters (per layer, per expert) are
+        the caller's to read from the step's metrics."""
         return {k: jnp.asarray(v).astype(jnp.float32)
-                for k, v in dict(metrics).items()}
+                for k, v in dict(metrics).items() if jnp.ndim(v) == 0}
 
     def update(self, metrics) -> None:
         self.count += 1

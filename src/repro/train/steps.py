@@ -11,6 +11,33 @@ import jax.numpy as jnp
 
 from repro.optim import optimizers as opt_lib
 
+# top-level params key of state that a step updates outside the optimizer
+# (a model's ``update_buffers(buffers, metrics, cfg)``): no gradient flows
+# into it and the optimizer holds no moments for it
+BUFFERS = "buffers"
+
+
+def split_buffers(params):
+    """(trainable params, buffers or None)."""
+    if isinstance(params, dict) and BUFFERS in params:
+        return ({k: v for k, v in params.items() if k != BUFFERS},
+                params[BUFFERS])
+    return params, None
+
+
+def trainable(params):
+    """What the optimizer sees: ``params`` without its buffers."""
+    return split_buffers(params)[0]
+
+
+def _sum_counts_mean_rest(stacked):
+    """Per-microbatch metrics -> per-step: integer counters add up, the
+    rest average."""
+    return jax.tree.map(
+        lambda x: (jnp.sum(x, axis=0)
+                   if jnp.issubdtype(x.dtype, jnp.integer)
+                   else jnp.mean(x, axis=0)), stacked)
+
 
 def _split_microbatches(batch, n: int):
     """Reshape every batch leaf to a leading microbatch axis.
@@ -60,35 +87,38 @@ def make_train_step(model, cfg, optimizer, policy, mesh=None,
 
     wrap_params = getattr(grad_reduce, "wrap_params", None)
 
-    def grad_of(params, mb):
+    def grad_of(params, buffers, mb):
         def loss(p):
             if wrap_params is not None:
                 p = wrap_params(p)
+            if buffers is not None:
+                p = dict(p, **{BUFFERS: jax.lax.stop_gradient(buffers)})
             with sharding_lib.seq_sharding(seq_shard):
                 return model.loss_fn(p, mb, cfg, policy=policy, mesh=mesh,
                                      remat=remat)
         return jax.value_and_grad(loss, has_aux=True)(params)
 
     def train_step(params, opt_state, batch):
+        params, buffers = split_buffers(params)
         if microbatches > 1:
             mbs = _split_microbatches(batch, microbatches)
 
             def body(acc, mb):
                 g_acc, l_acc = acc
-                (l, _), g = grad_of(params, mb)
+                (l, m), g = grad_of(params, buffers, mb)
                 g_acc = jax.tree.map(
                     lambda a, b: a + b.astype(jnp.float32), g_acc, g)
-                return (g_acc, l_acc + l), None
+                return (g_acc, l_acc + l), m
 
             g0 = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (grads, l), _ = jax.lax.scan(
+            (grads, l), ms = jax.lax.scan(
                 body, (g0, jnp.zeros((), jnp.float32)), mbs)
             grads = jax.tree.map(lambda g: g / microbatches, grads)
             l = l / microbatches
-            metrics = {}
+            metrics = _sum_counts_mean_rest(ms)
         else:
-            (l, metrics), grads = grad_of(params, batch)
+            (l, metrics), grads = grad_of(params, buffers, batch)
         if grad_reduce is not None:
             grads = grad_reduce(grads)
         if clip_norm:
@@ -97,6 +127,20 @@ def make_train_step(model, cfg, optimizer, policy, mesh=None,
             gnorm = opt_lib.global_norm(grads)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = opt_lib.apply_updates(params, updates)
+        if buffers is not None:
+            # replicas must agree on the new buffers: the custom loop's
+            # reducer means each replica's counts over all of them
+            seen = metrics
+            if wrap_params is not None:
+                raise NotImplementedError(
+                    "the overlap reducer reduces gradients inside the "
+                    "backward pass only; buffers need a flat or "
+                    "hierarchical grad_reduce")
+            if grad_reduce is not None:
+                seen = grad_reduce(jax.tree.map(
+                    lambda x: x.astype(jnp.float32), metrics))
+            params = dict(params, **{
+                BUFFERS: model.update_buffers(buffers, seen, cfg)})
         metrics = dict(metrics, loss=l, grad_norm=gnorm)
         return params, opt_state, metrics
 

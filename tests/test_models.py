@@ -17,6 +17,8 @@ from repro.train import steps as steps_lib
 
 POLICY = get_policy("f32")
 ARCHS = [a for a in config_base.ARCH_IDS if a != "calo3dgan"]
+# archs with a serving path (latent attention has no KV cache yet)
+DECODE_ARCHS = [a for a in ARCHS if config_base.get_config(a).decode_supported]
 B, S = 2, 128
 
 
@@ -62,6 +64,7 @@ def test_full_config_matches_assignment(arch):
         "xlstm-125m": (12, 768, 4, 4, 0, 50_304),
         "qwen2-1.5b": (28, 1536, 12, 2, 8960, 151_936),
         "phi4-mini-3.8b": (32, 3072, 24, 8, 8192, 200_064),
+        "moonlight-16b-a3b": (27, 2048, 16, 16, 11_264, 163_840),
     }[arch]
     cfg = config_base.get_config(arch)
     got = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
@@ -84,7 +87,8 @@ def test_smoke_train_step(arch):
 
     opt = opt_lib.adamw(1e-3)
     step = jax.jit(steps_lib.make_train_step(model, cfg, opt, POLICY))
-    p2, o2, metrics = step(params, opt.init(params), _train_batch(cfg))
+    p2, o2, metrics = step(params, opt.init(steps_lib.trainable(params)),
+                           _train_batch(cfg))
     loss = float(metrics["loss"])
     assert np.isfinite(loss) and loss > 0
     # params actually changed
@@ -93,7 +97,7 @@ def test_smoke_train_step(arch):
     assert max(jax.tree.leaves(delta)) > 0
 
 
-@pytest.mark.parametrize("arch", ARCHS)
+@pytest.mark.parametrize("arch", DECODE_ARCHS)
 def test_smoke_decode_step(arch):
     cfg = config_base.reduced_config(arch)
     model = api.get_model(cfg)

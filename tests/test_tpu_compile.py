@@ -228,3 +228,42 @@ def test_ssm_scan_zamba2_compiles(one_chip, direction):
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for s in shapes]
     _compile(_ssm_fwd if direction == "fwd" else _ssm_bwd, args, BLOCK_SHAPE)
+
+
+# the benchmark's moonlight-train-8k-ep8-1chip step: 5 layers of
+# moonlight-16b-a3b at published widths, 8 of 64 experts held, a 20480-id
+# vocabulary slice, 2 sequences of 8192 tokens, bf16 with f32 masters and
+# AdamW, through the engine's custom loop
+LM_CELL_PEAK_LIMIT = 15e9
+
+
+def test_moonlight_ep8_train_step_fits_one_v5e(topo):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.configs import moonlight_16b_a3b as ml
+    from repro.launch.mesh import make_mesh
+    from repro.models import api
+    from repro.optim import optimizers as opt_lib
+    from repro.substrate.precision import get_policy
+    from repro.train import engine as engine_lib
+
+    cfg = ml.config(n_layers=5, n_held=8, vocab=20480)
+    task = engine_lib.lm_task(api.get_model(cfg), cfg, opt_lib.adamw(ml.LR),
+                              policy=get_policy("bf16"))
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    eng = engine_lib.Engine(mesh, "custom", dp_axes=("data", "model"))
+    rep = NamedSharding(mesh, P())
+
+    def placed(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep)
+
+    batch = {"tokens": placed(jax.ShapeDtypeStruct((2, 8192), jnp.int32))}
+    state = jax.tree.map(placed, jax.eval_shape(
+        lambda: task.init(jax.random.key(0))))
+    key = placed(jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = eng.compile_step(task, batch).lower(state, batch, key) \
+        .compile()
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    print(f"moonlight-train-8k-ep8-1chip step peak: {peak / 1e9:.3f} GB")
+    assert "ragged" in compiled.as_text()       # the grouped expert matmuls
+    assert peak < LM_CELL_PEAK_LIMIT, peak
