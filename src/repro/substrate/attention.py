@@ -8,7 +8,7 @@ the same semantics (validated against this module's math via ref.py).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -96,111 +96,244 @@ def project_qkv(p, x, cfg):
 # ---------------------------------------------------------------------------
 
 
-def _attend_block(q, k, v, q_off, k_off, causal, window, scale):
+def _attend_block(q, k, v, mask, scale):
     """One (q-block, kv-block) tile with f32 score math.
 
-    q: (B, qc, KH, G, D)  k/v: (B, kc, KH, D) -> out (unnormalised), m, l.
+    q: (B, qc, KH, G, D)  k/v: (B, kc, KH, D)  mask: (qc, kc) -> out
+    (unnormalised), m, l.
     """
     s = jnp.einsum("bqkgd,bckd->bkgqc", q, k,
                    preferred_element_type=jnp.float32) * scale
-    qpos = q_off + jnp.arange(q.shape[1])
-    kpos = k_off + jnp.arange(k.shape[1])
-    mask = jnp.ones((q.shape[1], k.shape[1]), bool)
-    if causal:
-        mask &= qpos[:, None] >= kpos[None, :]
-    if window:
-        mask &= kpos[None, :] > qpos[:, None] - window
-    s = jnp.where(mask[None, None, None], s, -jnp.inf)
+    s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1)                                   # (B,KH,G,qc)
     # guard fully-masked rows
     m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
     e = jnp.exp(s - m_safe[..., None])
-    e = jnp.where(mask[None, None, None], e, 0.0)
+    e = jnp.where(mask, e, 0.0)
     l = jnp.sum(e, axis=-1)
     o = jnp.einsum("bkgqc,bckd->bqkgd", e.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     return o, m_safe, l
 
 
+def _tile_mask(qpos, kpos, T, causal, window):
+    """(len(qpos), len(kpos)) bool of one tile's counted pairs (keys past T
+    are the kv padding)."""
+    qpos, kpos = qpos[:, None], kpos[None, :]
+    mask = jnp.broadcast_to(kpos < T, (qpos.shape[0], kpos.shape[1]))
+    if causal:
+        mask &= qpos >= kpos
+    if window:
+        mask &= kpos > qpos - window
+    return mask
+
+
+class _Tiling(NamedTuple):
+    """Static arguments of the blockwise path: chunks already cut to S, T."""
+    causal: bool
+    window: int
+    q_chunk: int
+    kv_chunk: int
+    q_offset: int
+
+
 def blockwise_attention(q, k, v, *, causal=True, window=0,
-                        q_chunk=512, kv_chunk=512, q_offset=0,
-                        remat_blocks=False):
+                        q_chunk=512, kv_chunk=512, q_offset=0):
     """Memory-bounded attention with online softmax.
 
-    q: (B, S, H, D); k/v: (B, T, KH, D). GQA via head grouping.
-    Python loop over q blocks (static causal kv extent -> exact FLOPs),
-    lax.scan over kv blocks (O(1) HLO in T).  ``remat_blocks``
-    rematerialises each q block, so a backward pass holds one block's
-    scores at a time instead of every block's.
+    q: (B, S, H, D); k/v: (B, T, KH, D); v may be narrower than q/k.
+    GQA via head grouping.  Forward: a Python loop over q blocks (static
+    causal kv extent -> exact FLOPs), lax.scan over kv blocks (O(1) HLO
+    in T).  Backward (a custom VJP): each tile's probabilities are rebuilt
+    from the forward's per-row log-sum-exp, so residuals are O(S) and no
+    score tile is saved; dK/dV accumulate per kv block over the q blocks
+    that see it (scanned), dQ in place in its q block.
     """
     B, S, H, D = q.shape
     T, KH = k.shape[1], k.shape[2]
-    Dv = v.shape[-1]            # latent attention: v heads narrower than q/k
-    G = H // KH
+    t = _Tiling(bool(causal), int(window), min(q_chunk, S),
+                min(kv_chunk, T), int(q_offset))
+    o = _flash(q.reshape(B, S, KH, H // KH, D), k, v, t)
+    return o.reshape(B, S, H, v.shape[-1])
+
+
+def _pad_axis(x, axis, n, value=0):
+    """``x`` with ``n`` entries of ``value`` appended along ``axis``."""
+    if not n:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, n)
+    return jnp.pad(x, pad, constant_values=value)
+
+
+def _flash_forward(q, k, v, t: _Tiling):
+    """q: (B, S, KH, G, D); k/v: (B, T, KH, D[v]) -> out (B, S, KH, G, Dv)
+    f32 and the log-sum-exp of each row's scaled scores (B, KH, G, S) f32,
+    +inf on a row that sees no key (its probabilities then rebuild as 0)."""
+    B, S, KH, G, D = q.shape
+    T, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / (D ** 0.5)
-    q = q.reshape(B, S, KH, G, D)
-    q_chunk = min(q_chunk, S)
-    kv_chunk = min(kv_chunk, T)
-    # pad kv to a block multiple so dynamic_slice never clamps (the valid
-    # mask below zeroes the padded tail)
-    t_pad = (-T) % kv_chunk
-    if t_pad:
-        k = jnp.pad(k, ((0, 0), (0, t_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, t_pad), (0, 0), (0, 0)))
-    n_q = -(-S // q_chunk)
-    outs = []
-    for qi in range(n_q):
-        q_off = q_offset + qi * q_chunk
-        qlen = min(q_chunk, S - qi * q_chunk)
-        qb = jax.lax.dynamic_slice_in_dim(q, qi * q_chunk, qlen, axis=1)
+    # pad kv to a block multiple so dynamic_slice never clamps (the tile
+    # mask leaves the padded tail out)
+    k = _pad_axis(k, 1, (-T) % t.kv_chunk)
+    v = _pad_axis(v, 1, (-T) % t.kv_chunk)
+    # positions built outside the kv scans, so that no loop-invariant op
+    # is hoisted out of the caller's named scope
+    kidx = jnp.arange(t.kv_chunk)
+    outs, lses = [], []
+    for qi in range(-(-S // t.q_chunk)):
+        q_off = t.q_offset + qi * t.q_chunk
+        qlen = min(t.q_chunk, S - qi * t.q_chunk)
+        qb = jax.lax.dynamic_slice_in_dim(q, qi * t.q_chunk, qlen, axis=1)
         # causal: kv blocks beyond the end of this q block contribute nothing
-        if causal:
-            k_hi = min(T, q_off + qlen)
+        k_hi = min(T, q_off + qlen) if t.causal else T
+        k_lo = max(0, (q_off - t.window + 1) // t.kv_chunk * t.kv_chunk) \
+            if t.window and t.causal else 0
+        n_kv = max(1, -(-(k_hi - k_lo) // t.kv_chunk))
+        qpos = q_off + jnp.arange(qlen)
+
+        def body(carry, ki, qb=qb, qpos=qpos, k_lo=k_lo):
+            acc, m, l = carry
+            k_off = k_lo + ki * t.kv_chunk
+            o_b, m_b, l_b = _attend_block(
+                qb, jax.lax.dynamic_slice_in_dim(k, k_off, t.kv_chunk, 1),
+                jax.lax.dynamic_slice_in_dim(v, k_off, t.kv_chunk, 1),
+                _tile_mask(qpos, k_off + kidx, T, t.causal, t.window),
+                scale)
+            m_new = jnp.maximum(m, m_b)
+            a1 = jnp.exp(m - m_new)
+            a2 = jnp.exp(m_b - m_new)
+            acc = (acc * a1[..., None]
+                   + o_b.transpose(0, 2, 3, 1, 4) * a2[..., None])
+            l = l * a1 + l_b * a2
+            return (acc, m_new, l), None
+
+        acc0 = jnp.zeros((B, KH, G, qlen, Dv), jnp.float32)
+        # m must start finite for exp(m - m_new): large negative, not -inf
+        m0 = jnp.full((B, KH, G, qlen), -1e30)
+        l0 = jnp.zeros((B, KH, G, qlen), jnp.float32)
+        (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
+                                      jnp.arange(n_kv))
+        outs.append((acc / jnp.maximum(l[..., None], 1e-30))
+                    .transpose(0, 3, 1, 2, 4))
+        lses.append(jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)),
+                              jnp.inf))
+    return jnp.concatenate(outs, axis=1), jnp.concatenate(lses, axis=-1)
+
+
+def _q_runs(j, S, T, t: _Tiling):
+    """The q blocks whose tiles with kv block ``j`` hold a counted pair, as
+    runs [lo, hi) with whether the tile needs a mask (it crosses the
+    diagonal or the window edge, or holds kv padding).  A masked tile is a
+    run of its own; the tiles between need none."""
+    ka = j * t.kv_chunk
+    kz = min(T, ka + t.kv_chunk) - 1                  # last key held
+    runs = []
+    for i in range(-(-S // t.q_chunk)):
+        qa = t.q_offset + i * t.q_chunk
+        qz = t.q_offset + min(S, (i + 1) * t.q_chunk) - 1
+        if (t.causal and ka > qz) or (t.window and kz <= qa - t.window):
+            continue
+        masked = (ka + t.kv_chunk > T or (t.causal and qa < kz)
+                  or bool(t.window and ka <= qz - t.window))
+        if runs and not masked and not runs[-1][2] and runs[-1][1] == i:
+            runs[-1][1] = i + 1
         else:
-            k_hi = T
-        if window and causal:
-            k_lo = max(0, (q_off - window + 1) // kv_chunk * kv_chunk)
-        else:
-            k_lo = 0
-        n_kv = max(1, -(-(k_hi - k_lo) // kv_chunk))
+            runs.append([i, i + 1, masked])
+    return runs
 
-        def q_block(qb, k, v, q_off=q_off, k_lo=k_lo, qlen=qlen,
-                    n_kv=n_kv):
-            def body(carry, ki):
-                acc, m, l = carry
-                k_off = k_lo + ki * kv_chunk
-                kb = jax.lax.dynamic_slice_in_dim(k, k_off, kv_chunk, axis=1)
-                vb = jax.lax.dynamic_slice_in_dim(v, k_off, kv_chunk, axis=1)
-                # mask out positions past T (dynamic_slice clamps, so re-mask)
-                kpos = k_off + jnp.arange(kv_chunk)
-                valid = kpos < T
-                o_b, m_b, l_b = _attend_block(
-                    qb, jnp.where(valid[None, :, None, None], kb, 0),
-                    jnp.where(valid[None, :, None, None], vb, 0),
-                    q_off, k_off, causal, window, scale)
-                l_b = jnp.where(valid.any(), l_b, 0.0)
-                m_new = jnp.maximum(m, m_b)
-                a1 = jnp.exp(m - m_new)
-                a2 = jnp.exp(m_b - m_new)
-                acc = (acc * a1[..., None]
-                       + o_b.transpose(0, 2, 3, 1, 4) * a2[..., None])
-                l = l * a1 + l_b * a2
-                return (acc, m_new, l), None
 
-            acc0 = jnp.zeros((B, KH, G, qlen, Dv), jnp.float32)
-            # m must start finite for exp(m - m_new): large negative, not -inf
-            m0 = jnp.full((B, KH, G, qlen), -1e30)
-            l0 = jnp.zeros((B, KH, G, qlen), jnp.float32)
-            (acc, m, l), _ = jax.lax.scan(body, (acc0, m0, l0),
-                                          jnp.arange(n_kv))
-            return acc / jnp.maximum(l[..., None], 1e-30)
+def _tile_grads(qb, dob, lse, dr, kb, vb, mask, scale):
+    """One tile's gradients from its rebuilt probabilities; f32 math,
+    operands of every matmul in the inputs' dtype.
 
-        if remat_blocks:
-            q_block = jax.checkpoint(q_block)
-        o = q_block(qb, k, v)
-        outs.append(o.transpose(0, 3, 1, 2, 4).reshape(B, qlen, H, Dv))
-    return jnp.concatenate(outs, axis=1).astype(v.dtype) if len(outs) > 1 \
-        else outs[0].astype(v.dtype)
+    qb: (B, qc, KH, G, D), dob: (B, qc, KH, G, Dv), lse/dr: (B, KH, G, qc),
+    kb/vb: (B, kc, KH, D[v]) -> dq (B, qc, KH, G, D), dk, dv (B, kc, ...)
+    in f32."""
+    f32 = jnp.float32
+    s = jnp.einsum("bqkgd,bckd->bkgqc", qb, kb, preferred_element_type=f32)
+    p = jnp.exp(s * scale - lse[..., None])
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    dv = jnp.einsum("bkgqc,bqkgd->bckd", p.astype(vb.dtype), dob,
+                    preferred_element_type=f32)
+    dp = jnp.einsum("bqkgd,bckd->bkgqc", dob, vb, preferred_element_type=f32)
+    ds = (p * (dp - dr[..., None]) * scale).astype(qb.dtype)
+    dk = jnp.einsum("bkgqc,bqkgd->bckd", ds, qb, preferred_element_type=f32)
+    dq = jnp.einsum("bkgqc,bckd->bqkgd", ds, kb, preferred_element_type=f32)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash(q, k, v, t: _Tiling):
+    return _flash_forward(q, k, v, t)[0].astype(v.dtype)
+
+
+def _flash_fwd(q, k, v, t: _Tiling):
+    o, lse = _flash_forward(q, k, v, t)
+    return o.astype(v.dtype), (q, k, v, o, lse)
+
+
+def _flash_bwd(t: _Tiling, res, do):
+    """dQ, dK, dV.  Outer loop over kv blocks, each with a kv-block-sized
+    f32 dK/dV carry over the q blocks that see it (written once); dQ is one
+    f32 buffer that each tile adds into its own q block."""
+    q, k, v, o, lse = res
+    B, S, KH, G, D = q.shape
+    T = k.shape[1]
+    qc, kc = t.q_chunk, t.kv_chunk
+    n_q, n_k = -(-S // qc), -(-T // kc)
+    scale = 1.0 / (D ** 0.5)
+    dr = jnp.einsum("bqkgd,bqkgd->bkgq", do.astype(jnp.float32), o)
+    # q rows past S: lse +inf rebuilds their probabilities as 0
+    sp = n_q * qc - S
+    qp, dop = _pad_axis(q, 1, sp), _pad_axis(do, 1, sp)
+    lse, dr = _pad_axis(lse, 3, sp, jnp.inf), _pad_axis(dr, 3, sp)
+    kp = _pad_axis(k, 1, n_k * kc - T)
+    vp = _pad_axis(v, 1, n_k * kc - T)
+
+    def tile(carry, i, kb, vb, mask):
+        dq, dk, dv = carry
+        a = i * qc
+        dq_b, dk_b, dv_b = _tile_grads(
+            jax.lax.dynamic_slice_in_dim(qp, a, qc, axis=1),
+            jax.lax.dynamic_slice_in_dim(dop, a, qc, axis=1),
+            jax.lax.dynamic_slice_in_dim(lse, a, qc, axis=3),
+            jax.lax.dynamic_slice_in_dim(dr, a, qc, axis=3),
+            kb, vb, mask, scale)
+        dq = jax.lax.dynamic_update_slice_in_dim(
+            dq, jax.lax.dynamic_slice_in_dim(dq, a, qc, axis=1) + dq_b, a,
+            axis=1)
+        return dq, dk + dk_b, dv + dv_b
+
+    dq = jnp.zeros(qp.shape, jnp.float32)
+    dks, dvs = [], []
+    for j in range(n_k):
+        kb = kp[:, j * kc:(j + 1) * kc]
+        vb = vp[:, j * kc:(j + 1) * kc]
+        carry = (dq, jnp.zeros(kb.shape, jnp.float32),
+                 jnp.zeros(vb.shape, jnp.float32))
+        for lo, hi, masked in _q_runs(j, S, T, t):
+            if masked:
+                mask = _tile_mask(t.q_offset + lo * qc + jnp.arange(qc),
+                                  j * kc + jnp.arange(kc), T, t.causal,
+                                  t.window)
+                carry = tile(carry, lo, kb, vb, mask)
+            else:
+                carry, _ = jax.lax.scan(
+                    lambda c, i, kb=kb, vb=vb: (tile(c, i, kb, vb, None),
+                                                None),
+                    carry, jnp.arange(lo, hi))
+        dq, dk, dv = carry
+        dks.append(dk)
+        dvs.append(dv)
+    dk = jnp.concatenate(dks, axis=1)[:, :T]
+    dv = jnp.concatenate(dvs, axis=1)[:, :T]
+    return (dq[:, :S].astype(q.dtype), dk.astype(k.dtype),
+            dv.astype(v.dtype))
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def attend(q, k, v, *, causal=True, window=0, use_pallas=False,
@@ -333,5 +466,5 @@ def apply_mla(p, x, cos, sin, cfg):
         if S <= 1024:
             o = dot_attention(q, k, v, causal=True)
         else:
-            o = blockwise_attention(q, k, v, causal=True, remat_blocks=True)
+            o = blockwise_attention(q, k, v, causal=True)
         return layers.apply_dense(p["wo"], o.reshape(B, S, H * a.v_head_dim))

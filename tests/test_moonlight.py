@@ -7,6 +7,7 @@ the correction bias, and the launcher."""
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -216,8 +217,8 @@ def test_logits_loss_and_gradients_match_float32_reference():
 @pytest.mark.parametrize("S", [64, 1280])
 def test_mla_matches_per_head_naive_attention(S):
     """S=64 takes the dense path, S=1280 the blockwise path with its
-    rematerialised tiles.  Tolerance 1e-4 relative: float32 both sides,
-    online softmax against one softmax."""
+    custom VJP.  Tolerance 1e-4 relative: float32 both sides, online
+    softmax against one softmax."""
     cfg = ml.reduced()
     p = attn_lib.init_mla(jax.random.key(3), cfg)
     x = jax.random.normal(jax.random.key(4), (1, S, cfg.d_model))
@@ -226,7 +227,7 @@ def test_mla_matches_per_head_naive_attention(S):
                                      cfg.rope_theta)
     with jax.default_matmul_precision("highest"):
         got = attn_lib.apply_mla(p, x, cos, sin, cfg)
-        # through a gradient too: the remat path must differentiate
+        # through a gradient too: the blockwise path's own backward
         g = jax.grad(lambda xx: jnp.sum(
             attn_lib.apply_mla(p, xx, cos, sin, cfg) ** 2))(x)
     want = ref_mla(p, x[0], cfg)
@@ -236,6 +237,47 @@ def test_mla_matches_per_head_naive_attention(S):
     g_ref = jax.grad(lambda xx: jnp.sum(ref_mla(p, xx, cfg) ** 2))(x[0])
     np.testing.assert_allclose(np.asarray(g[0]), np.asarray(g_ref),
                                atol=1e-4 * float(jnp.max(jnp.abs(g_ref))))
+
+
+_HLO_OPCODE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?\s([a-z][\w-]*)\(")
+
+
+def test_mla_gradient_ops_land_under_mla_in_the_backward_pass():
+    """The blockwise path's custom VJP inside the layer's checkpoint, as
+    ``models/lm._mla_stack`` runs it, compiled at S=1280: through the
+    benchmark's ``lm_scopes.scope_of``, every op of the backward pass (the
+    rematerialised forward and the VJP's tile loops) reads ``mla``/``bwd``,
+    every op of an attention loop (a ``while`` body) reads ``mla``, and the
+    backward's tile matmuls are among them."""
+    bench = os.path.join(REPO, "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from harness import lm_scopes
+
+    cfg = ml.reduced()
+    S = 1280
+    p = attn_lib.init_mla(jax.random.key(3), cfg)
+    x = jax.random.normal(jax.random.key(4), (1, S, cfg.d_model))
+    cos, sin = attn_lib.rope_cos_sin(jnp.arange(S)[None],
+                                     cfg.mla.qk_rope_head_dim, cfg.rope_theta)
+    layer = jax.checkpoint(lambda p, x: attn_lib.apply_mla(p, x, cos, sin,
+                                                           cfg))
+    grad = jax.jit(jax.grad(lambda p, x: jnp.sum(layer(p, x) ** 2),
+                            argnums=(0, 1)))
+    text = grad.lower(p, x).compile().as_text()
+    names = lm_scopes.op_scopes(text)
+    opcode = {m.group(1): m.group(2) for m in map(_HLO_OPCODE.match,
+                                                  text.splitlines()) if m}
+    bwd = {n: op for n, op in names.items() if "rematted_computation" in op}
+    assert bwd
+    assert {op for op in bwd.values()
+            if lm_scopes.scope_of(op) != ("mla", "bwd")} == set()
+    loops = {op for op in names.values() if "/while/" in op}
+    assert loops
+    assert {op for op in loops if lm_scopes.scope_of(op)[0] != "mla"} == set()
+    tile_dots = [n for n, op in bwd.items()
+                 if opcode.get(n) == "dot" and "/while/" in op]
+    assert len(tile_dots) >= 5          # s, dV, dP, dK, dQ of a tile
 
 
 def _chunked_cfg():

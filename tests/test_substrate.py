@@ -36,6 +36,62 @@ def test_blockwise_equals_dot_attention(S, H, KH, D, window):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("causal,q_offset", [(False, 0), (True, 37)])
+def test_blockwise_leaves_kv_padding_out(causal, q_offset):
+    """T=300 pads the last kv block of 64 with 20 zero keys; queries that
+    may see past T (no causal mask, or positions past 300) must still not
+    count them.  A Whisper encoder's 1500 frames take this path."""
+    S = 300
+    q = _randn((2, S, 4, 32))
+    k = _randn((2, S, 2, 32))
+    v = _randn((2, S, 2, 32))
+    qpos = jnp.broadcast_to(q_offset + jnp.arange(S)[None], (2, S))
+    blk = attn_lib.blockwise_attention(q, k, v, causal=causal, q_chunk=64,
+                                       kv_chunk=64, q_offset=q_offset)
+    ref = attn_lib.dot_attention(q, k, v, causal=causal, q_positions=qpos)
+    np.testing.assert_allclose(np.asarray(blk), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("S,H,KH,D,Dv,window,causal,q_offset", [
+    # the four cases of test_blockwise_equals_dot_attention
+    (256, 4, 2, 32, 32, 0, True, 0), (256, 4, 4, 32, 32, 0, True, 0),
+    (300, 8, 1, 16, 16, 0, True, 0), (256, 4, 2, 32, 32, 64, True, 0),
+    # latent attention's head widths: q/k 192, v 128
+    (256, 4, 4, 192, 128, 0, True, 0),
+    (256, 4, 2, 32, 32, 0, False, 0),
+    # last q and kv blocks partial (300 = 4 x 64 + 44)
+    (300, 4, 2, 32, 32, 0, True, 0),
+    # queries start at 100 (a chunk after a cached prefix)
+    (256, 4, 2, 32, 32, 0, True, 100),
+])
+def test_blockwise_grad_equals_dot_attention(S, H, KH, D, Dv, window, causal,
+                                             q_offset):
+    """dq, dk, dv of the blockwise path's custom VJP (probabilities rebuilt
+    from the saved log-sum-exp) against autodiff of one softmax, float32,
+    at the forward test's tolerance."""
+    q = _randn((2, S, H, D))
+    k = _randn((2, S, KH, D))
+    v = _randn((2, S, KH, Dv))
+    w = _randn((2, S, H, Dv))
+    qpos = jnp.broadcast_to(q_offset + jnp.arange(S)[None], (2, S))
+
+    def blk(q, k, v):
+        return jnp.sum(w * attn_lib.blockwise_attention(
+            q, k, v, causal=causal, window=window, q_chunk=64, kv_chunk=64,
+            q_offset=q_offset))
+
+    def ref(q, k, v):
+        return jnp.sum(w * attn_lib.dot_attention(
+            q, k, v, causal=causal, window=window, q_positions=qpos))
+
+    got = jax.grad(blk, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+    for name, g, r in zip("qkv", got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5,
+                                   rtol=2e-5, err_msg=f"d{name}")
+
+
 def test_dot_attention_kv_len_masks_cache_tail():
     """Decode semantics: keys beyond kv_len must not contribute."""
     q = _randn((2, 1, 4, 16))
